@@ -7,9 +7,12 @@ two MXU matmuls.  CUDA-Punica's warp-gather has no TPU analogue; the
 data-dependent index_map is the TPU-native equivalent (the gather happens in
 the DMA engine, overlapped with compute by the Pallas pipeline).
 
-Tokens inside a block share the gathered adapter, so the wrapper pads the
-token axis to the block size and uses block=1 tokens for the fully general
-case (decode batches are small — this is exactly Punica's BGMV regime).
+Each grid step handles one token, the fully general case (decode batches
+are small — this is exactly Punica's BGMV regime).  Mosaic requires the
+last two dims of a block to be (8, 128)-aligned or to equal the array's,
+so the token axis is a leading dim: x is viewed as (T, 1, d) and y as
+(T, 1, o), and each step's (1, 1, d) / (1, 1, o) block spans the last two
+dims whole.
 """
 from __future__ import annotations
 
@@ -23,7 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _bgmv_kernel(idx_ref, x_ref, a_ref, b_ref, o_ref, *, scale: float):
     i = pl.program_id(0)
-    x = x_ref[...]                                    # (1, d)
+    x = x_ref[0]                                      # (1, d)
     a = a_ref[0]                                      # (d, r)
     b = b_ref[0]                                      # (r, o)
     h = jnp.dot(x.astype(jnp.float32), a.astype(jnp.float32),
@@ -33,7 +36,7 @@ def _bgmv_kernel(idx_ref, x_ref, a_ref, b_ref, o_ref, *, scale: float):
     # idx < 0 = base-model token: the index map clamped the DMA to
     # adapter 0; mask its contribution to a zero delta here.
     y = jnp.where(idx_ref[i] >= 0, y * scale, 0.0)
-    o_ref[...] = y.astype(o_ref.dtype)
+    o_ref[0] = y.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -57,13 +60,13 @@ def bgmv(x, a, b, idx, scale: float = 1.0, interpret: bool = False):
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, d), lambda i, idx_ref: (i, 0)),
+                pl.BlockSpec((1, 1, d), lambda i, idx_ref: (i, 0, 0)),
                 pl.BlockSpec((1, d, r), _ab_map),
                 pl.BlockSpec((1, r, o), _ab_map),
             ],
-            out_specs=pl.BlockSpec((1, o), lambda i, idx_ref: (i, 0)),
+            out_specs=pl.BlockSpec((1, 1, o), lambda i, idx_ref: (i, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((t, o), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((t, 1, o), x.dtype),
         interpret=interpret,
-    )(idx.astype(jnp.int32), x, a, b)
-    return out
+    )(idx.astype(jnp.int32), x.reshape(t, 1, d), a, b)
+    return out.reshape(t, o)

@@ -26,10 +26,7 @@ KERNEL_MODES = ("pallas", "ref", "interpret")
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def lora_apply(x, a, b, idx, scale: float = 1.0, ranks=None,

@@ -7,16 +7,10 @@ this module never locks the jax device count — required for the dry-run's
 from __future__ import annotations
 
 import jax
-
-try:                                      # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:                       # older jax: meshes are Auto-typed
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mk(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -1,8 +1,14 @@
-"""Serving launcher: run the multi-adapter engine on a reduced model with
-the real JAX executor, under a Poisson multi-adapter workload.
+"""Serving launcher: run the multi-adapter engine with the real JAX executor
+under a Poisson multi-adapter workload.
 
-    python -m repro.launch.serve --arch phi4-mini-3.8b --adapters 8 \
-        --slots 4 --rate 0.5 --horizon 30
+On the chip, the published config (random weights from a seed):
+
+    python -m repro.launch.serve --arch phi4-mini-3.8b --horizon 3
+
+On the CPU, the tiny structurally identical preset:
+
+    JAX_PLATFORMS=cpu python -m repro.launch.serve --arch phi4-mini-3.8b \
+        --reduced --adapters 8 --slots 4 --rate 0.5 --horizon 30
 """
 from __future__ import annotations
 
@@ -10,11 +16,13 @@ import argparse
 
 import jax
 
-from ..configs import get_reduced
+from ..configs import get_config, get_reduced
 from ..core.workload import WorkloadSpec, generate_requests, make_adapter_pool
 from ..models import Model, ShardingPlan
 from ..serving import EngineConfig, JaxExecutor, ServingEngine
+from ..serving.metrics import ServingMetrics
 from ..serving.policy import SCHED_POLICIES
+from .compile_cache import enable_compile_cache
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -22,6 +30,8 @@ def build_parser() -> argparse.ArgumentParser:
     documented flags against the real parser)."""
     ap = argparse.ArgumentParser(prog="python -m repro.launch.serve")
     ap.add_argument("--arch", default="phi4-mini-3.8b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="CPU-sized config (smoke/demo)")
     ap.add_argument("--adapters", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--rate", type=float, default=0.5)
@@ -35,25 +45,36 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main() -> None:
-    args = build_parser().parse_args()
-
-    cfg = get_reduced(args.arch)
+def build_executor(args: argparse.Namespace) -> JaxExecutor:
+    """The model, its weights and LoRA bank (random from seed 0), and the
+    executor, warmed up so the decode compile stays out of the served
+    window.  Weights are built under ``jax.jit`` so the f32 draws that
+    ``dense_init`` casts from never sit whole in device memory."""
+    cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     model = Model(cfg, ShardingPlan(mode="decode"))
     key = jax.random.PRNGKey(0)
-    params = model.init(key)
-    lora = model.init_lora(key, max(args.slots, 1), args.rank)
-    executor = JaxExecutor(model, params, lora, max_batch=8, cache_len=512)
+    params = jax.jit(model.init)(key)
+    lora = jax.jit(model.init_lora, static_argnums=(1, 2))(
+        key, max(args.slots, 1), args.rank)
+    return JaxExecutor(model, params, lora, max_batch=8, cache_len=512)
 
+
+def serve(args: argparse.Namespace, executor: JaxExecutor) -> ServingMetrics:
+    """Serve the seeded Poisson workload through ``ServingEngine``."""
     pool = make_adapter_pool(args.adapters, [args.rank], [args.rate])
     spec = WorkloadSpec(adapters=pool, dataset=args.dataset,
                         horizon=args.horizon)
-    reqs = generate_requests(spec)
     engine = ServingEngine(EngineConfig(
         kv_capacity_tokens=args.kv_tokens, adapter_slots=args.slots,
         sched_policy=args.sched_policy),
         executor)
-    m = engine.run(reqs, horizon=args.horizon)
+    return engine.run(generate_requests(spec), horizon=args.horizon)
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    enable_compile_cache()
+    m = serve(args, build_executor(args))
     print(f"served {m.n_finished} requests | throughput={m.throughput:.1f} "
           f"tok/s (ideal {m.ideal_throughput:.1f}) | itl={m.itl * 1e3:.1f}ms "
           f"| ttft={m.ttft * 1e3:.1f}ms "

@@ -32,20 +32,6 @@ from . import attention, layers, moe as moe_lib, rglru as rglru_lib, ssm
 from .config import ModelConfig
 from .sharding import ShardingPlan
 
-try:  # jax >= 0.8
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(*args, check_vma=False, **kwargs):
-    """jax.shard_map across jax versions (check_vma was check_rep)."""
-    kwargs[_CHECK_KW] = check_vma
-    return _shard_map(*args, **kwargs)
-
-
 @dataclasses.dataclass(frozen=True)
 class Segment:
     kinds: Tuple[str, ...]
@@ -291,8 +277,8 @@ class Model:
         q = plan.constrain(q, spec)
         k = plan.constrain(k, spec)
         v = plan.constrain(v, spec)
-        return shard_map(body, mesh=plan.mesh, in_specs=(spec,) * 3,
-                         out_specs=spec, check_vma=False)(q, k, v)
+        return jax.shard_map(body, mesh=plan.mesh, in_specs=(spec,) * 3,
+                             out_specs=spec, check_vma=False)(q, k, v)
 
     def _prefill_cache(self, k, v, kind, s):
         cfg = self.cfg
@@ -355,8 +341,10 @@ class Model:
                 return attention.decode_attention_sharded(
                     q, kc, vc, nk, nv, p, axis_name=axis, n_shards=n,
                     scale=scale, k_scale=ks, v_scale=vs)
-        outs = shard_map(body, mesh=plan.mesh, in_specs=tuple(in_specs),
-                         out_specs=tuple(out_specs), check_vma=False)(*args)
+        outs = jax.shard_map(body, mesh=plan.mesh,
+                             in_specs=tuple(in_specs),
+                             out_specs=tuple(out_specs),
+                             check_vma=False)(*args)
         return outs[0][:, None], _pack_kv(outs, quant)
 
     def _ffn(self, p, x):
@@ -395,7 +383,7 @@ class Model:
             return out.reshape(bl, sl, d), aux
 
         moe_p = {k: plan.constrain(v, espec[k]) for k, v in p["moe"].items()}
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             body, mesh=plan.mesh, in_specs=(espec, xspec),
             out_specs=(xspec, P()), check_vma=False)(
                 moe_p, plan.constrain(x, xspec))

@@ -1,8 +1,8 @@
 """Executors: supply the step-time components of Eq. (1) to the engine.
 
-``JaxExecutor`` actually runs a (reduced) model's prefill/decode with
-per-request LoRA adapters through the real JAX code path and reports
-measured wall times — the honest closed loop used by the tests.
+``JaxExecutor`` runs a model's decode step with per-request LoRA adapters
+through the real JAX code path and reports measured wall times: the
+published config on a TPU chip, or a reduced one on the CPU in tests.
 
 ``SyntheticExecutor`` reports times from a hidden hardware profile
 (defaults calibrated to the paper's H100 + Llama-3.1-8B magnitudes).  It
@@ -98,7 +98,8 @@ class SyntheticExecutor:
 
 
 class JaxExecutor:
-    """Runs a real reduced model on CPU, one decode step per engine step.
+    """Runs a real model, one decode step per engine step, on whatever
+    device JAX gives it (the TPU chip, or the CPU in tests).
 
     Uses padded static batch shapes (requests packed into a fixed-capacity
     batch with an active mask) so every step hits the same jit cache entry.
@@ -113,13 +114,14 @@ class JaxExecutor:
         self.params = params
         self.lora = lora
         self.max_batch = max_batch
-        self.cache = model.init_cache(max_batch, cache_len)
+        self.cache = jax.jit(model.init_cache, static_argnums=(0, 1))(
+            max_batch, cache_len)
         self.tokens = jnp.zeros((max_batch, 1), jnp.int32)
-        self._decode = jax.jit(model.decode_step)
+        self.decode = jax.jit(model.decode_step)
         self._slot_of: Dict[int, int] = {}
         # warmup
         idx = jnp.zeros((max_batch,), jnp.int32)
-        out = self._decode(params, lora, self.cache, self.tokens, idx)
+        out = self.decode(params, lora, self.cache, self.tokens, idx)
         jax.block_until_ready(out[0])
 
     def step(self, plan: StepPlan, n_waiting: int) -> StepTiming:
@@ -131,7 +133,7 @@ class JaxExecutor:
         t_sched = time.perf_counter() - t0
 
         t1 = time.perf_counter()
-        logits, self.cache = self._decode(
+        logits, self.cache = self.decode(
             self.params, self.lora, self.cache, self.tokens,
             jnp.asarray(idx))
         self.jax.block_until_ready(logits)

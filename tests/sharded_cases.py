@@ -24,11 +24,6 @@ from repro.models.transformer import pad_cache  # noqa: E402
 KEY = jax.random.PRNGKey(2)
 
 
-def use_mesh(mesh):
-    """jax.set_mesh on new jax; the Mesh context manager on old jax."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-
-
 def put(tree, specs, mesh):
     return jax.tree.map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), tree, specs,
@@ -53,7 +48,7 @@ def case_train(arch):
     params_sh = put(params, plan.param_specs(params), mesh)
     batch_sh = {"tokens": jax.device_put(
         batch["tokens"], NamedSharding(mesh, P("data", None)))}
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         loss_sh = jax.jit(model.train_loss)(params_sh, batch_sh)
     # MoE aux-balance loss is estimated per data shard under EP (different
     # token subsets), so allow a slightly looser budget for MoE families.
@@ -75,7 +70,7 @@ def case_grad(arch):
     params_sh = put(params, plan.param_specs(params), mesh)
     batch_sh = {"tokens": jax.device_put(
         batch["tokens"], NamedSharding(mesh, P("data", None)))}
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         g_sh = jax.jit(jax.grad(model.train_loss))(params_sh, batch_sh)
     errs = jax.tree.map(
         lambda a, b: float(np.max(np.abs(np.asarray(a) - np.asarray(b)))
@@ -107,7 +102,7 @@ def case_decode(arch, batch=4):
     tok_sh = jax.device_put(tokens[:, -1:],
                             NamedSharding(mesh, P(dp, None)))
     idx_sh = jax.device_put(idx, NamedSharding(mesh, P(dp)))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits_sh, _ = jax.jit(model.decode_step)(
             params_sh, repl(mesh, lora), cache_sh, tok_sh, idx_sh)
     err = float(jnp.max(jnp.abs(logits_ref - logits_sh)))
@@ -124,9 +119,8 @@ def case_compression():
     def body(xl):
         return quantized_psum(xl[0], "data", 8)
 
-    from repro.models.transformer import shard_map
-    f = shard_map(body, mesh=mesh, in_specs=P("data", None),
-                  out_specs=P(None), check_vma=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=P("data", None),
+                      out_specs=P(None), check_vma=False)
     got = np.asarray(f(x))
     want = np.asarray(x.sum(0))
     scale = np.abs(x).max() / 127.0
