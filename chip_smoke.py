@@ -86,7 +86,17 @@ def lora_agreement(jax, jnp, ops, lora, target: str) -> float:
 
 
 def main() -> int:
+    # the package comes from this checkout's src/, so the script runs from
+    # the repo root; imported before any device is touched, so that a copy
+    # outside the checkout fails without taking the chip
+    sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
     import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.launch import serve
+    from repro.launch.compile_cache import enable_compile_cache
 
     devices = jax.devices()
     dev = devices[0]
@@ -96,14 +106,6 @@ def main() -> int:
         return 2
     print(f"device: platform={dev.platform} kind={dev.device_kind} "
           f"count={len(devices)}")
-
-    sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
-    import jax.numpy as jnp
-
-    from repro.configs import get_config
-    from repro.kernels import ops
-    from repro.launch import serve
-    from repro.launch.compile_cache import enable_compile_cache
 
     print(f"compile cache: {enable_compile_cache()}")
     compiles = compile_log(jax)

@@ -2,8 +2,9 @@
 
 ``JAX_COMPILATION_CACHE_DIR``, when set, names the directory: JAX reads the
 variable itself and nothing here overrides it.  Otherwise the cache lives
-in one fixed, git-ignored directory of the checkout.  The directory is part
-of the cache key, so it never comes from a temp name, a pid or the time.
+in one fixed, git-ignored directory of the checkout, so that a later run
+finds what an earlier one cached: it never comes from a temp name, a pid
+or the time.
 """
 from __future__ import annotations
 

@@ -32,19 +32,23 @@ N_SLOTS, RANK, BATCH, CACHE_LEN = 4, 8, 8, 512
 def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    try:
-        desc = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    # a compile for a described chip is written to the persistent cache
-    # but cannot be read back without one: keep the cache out of it
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield desc
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+    with pytest.MonkeyPatch.context() as mp:
+        # loading the TPU library otherwise writes driver logs to a fixed
+        # directory shared by every process on the machine
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip is written to the persistent cache
+        # but cannot be read back without one: keep the cache out of it
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        yield desc
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
 
 
 @pytest.fixture(scope="module")
