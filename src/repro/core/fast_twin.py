@@ -45,7 +45,7 @@ from typing import Deque, Dict, List, Optional
 
 import numpy as np
 
-from ..serving.engine import EngineConfig, StepTrace
+from ..serving.engine import EngineConfig
 from ..serving.metrics import ServingMetrics, ttft_percentiles
 from ..serving.policy import (SchedView, make_sched_policy,
                               overrides_on_admit, overrides_victim)
@@ -292,7 +292,6 @@ class FastEngine:
         self._total_blocks = max(int(cfg.kv_capacity_tokens)
                                  // cfg.block_size, 0)
         self._max_running = cfg.max_running
-        self.trace: List[StepTrace] = []
         self._sched_view = _SchedCounts(self)
         self._policy_view = _RowView(self)
         self.reset_stream()
@@ -771,7 +770,7 @@ class FastEngine:
 
     # ------------------------------------------------------------------ #
     def run_until(self, t_end: Optional[float] = None,
-                  record_trace: bool = False, strict: bool = False) -> None:
+                  strict: bool = False) -> None:
         """Advance the continuous-batching loop (see
         ``ServingEngine.run_until`` — identical control flow)."""
         if self.halted:
@@ -831,9 +830,6 @@ class FastEngine:
                 if total_blocks else 1.0
             if kv_used > self._max_kv:
                 self._max_kv = kv_used
-            if record_trace:
-                self.trace.append(StepTrace(
-                    t, r_run, n_wait, kv_used, total))
             self._finish_step(t)
             self.clock = t
 
@@ -1027,12 +1023,10 @@ class FastEngine:
 
     # ------------------------------------------------------------------ #
     def run(self, requests: List[Request], horizon: Optional[float] = None,
-            record_trace: bool = False,
             fresh: bool = False) -> ServingMetrics:
         self.reset_stream()
         self.submit(requests, fresh=fresh)
-        self.run_until(horizon if horizon is not None else math.inf,
-                       record_trace=record_trace)
+        self.run_until(horizon if horizon is not None else math.inf)
         return self.finalize()
 
 

@@ -244,13 +244,14 @@ def quantize_kv(x):
 def decode_update_cache(cache, new, pos, my, s_loc):
     """Masked append of `new` (B, KV, ...) into the local slice
     (B, S_loc, KV, ...) — works for values (4-d) and scales (3-d)."""
-    local = pos - my * s_loc
-    ok = (local >= 0) & (local < s_loc)
-    idx = jnp.clip(local, 0, s_loc - 1)
-    start = (0, idx) + (0,) * (cache.ndim - 2)
-    upd = jax.lax.dynamic_update_slice(
-        cache, new[:, None].astype(cache.dtype), start)
-    return jnp.where(ok, upd, cache)
+    with jax.named_scope("kv_update"):
+        local = pos - my * s_loc
+        ok = (local >= 0) & (local < s_loc)
+        idx = jnp.clip(local, 0, s_loc - 1)
+        start = (0, idx) + (0,) * (cache.ndim - 2)
+        upd = jax.lax.dynamic_update_slice(
+            cache, new[:, None].astype(cache.dtype), start)
+        return jnp.where(ok, upd, cache)
 
 
 def decode_attention_sharded(q, k_cache, v_cache, new_k, new_v, pos, *,
@@ -325,10 +326,11 @@ def decode_attention_rolling(q, k_cache, v_cache, new_k, new_v, pos, *,
     g = h // kvh
     w = k_cache.shape[1]
     slot = pos % w
-    k_cache = jax.lax.dynamic_update_slice(
-        k_cache, new_k[:, None].astype(k_cache.dtype), (0, slot, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(
-        v_cache, new_v[:, None].astype(v_cache.dtype), (0, slot, 0, 0))
+    with jax.named_scope("kv_update"):
+        k_cache = jax.lax.dynamic_update_slice(
+            k_cache, new_k[:, None].astype(k_cache.dtype), (0, slot, 0, 0))
+        v_cache = jax.lax.dynamic_update_slice(
+            v_cache, new_v[:, None].astype(v_cache.dtype), (0, slot, 0, 0))
     slots = jnp.arange(w)
     # global position stored in each slot (largest p <= pos with p % w == slot)
     kv_pos = pos - ((pos - slots) % w)

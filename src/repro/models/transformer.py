@@ -202,44 +202,52 @@ class Model:
         if lora_p is not None and f"a_{name}" in lora_p and \
                 adapter_idx is not None:
             from .. import kernels
-            delta = kernels.ops.lora_apply(
-                h, lora_p[f"a_{name}"], lora_p[f"b_{name}"], adapter_idx)
-            out = out + delta.astype(out.dtype)
+            with jax.named_scope("lora"):
+                delta = kernels.ops.lora_apply(
+                    h, lora_p[f"a_{name}"], lora_p[f"b_{name}"], adapter_idx)
+                out = out + delta.astype(out.dtype)
         return out
 
     def _attention_mixer(self, p, lora_p, cache, x, kind, adapter_idx):
         cfg, plan = self.cfg, self.plan
         b, s, _ = x.shape
         hd, nq, nkv = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
-        h = layers.apply_norm(cfg.norm, p["norm1"], x)
-        q = self._attn_proj(p, lora_p, h, "q", adapter_idx)
-        k = self._attn_proj(p, lora_p, h, "k", adapter_idx)
-        v = self._attn_proj(p, lora_p, h, "v", adapter_idx)
+        # named scopes put each part's operations under one name in the
+        # compiled program's metadata, so that a profile can sum them
+        with jax.named_scope("attn_proj"):
+            h = layers.apply_norm(cfg.norm, p["norm1"], x)
+            q = self._attn_proj(p, lora_p, h, "q", adapter_idx)
+            k = self._attn_proj(p, lora_p, h, "k", adapter_idx)
+            v = self._attn_proj(p, lora_p, h, "v", adapter_idx)
         q = q.reshape(b, s, nq, hd)
         k = k.reshape(b, s, nkv, hd)
         v = v.reshape(b, s, nkv, hd)
         scale = 1.0 / math.sqrt(hd)
 
         decode = plan.mode == "decode"
-        if decode:
-            pos = cache["pos"]
-            positions = jnp.full((b, 1), pos)
-        else:
-            positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-        if cfg.pos_emb == "rope":
-            q = layers.apply_rope(q, positions, cfg.rope_theta)
-            k = layers.apply_rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("attention"):
+            if decode:
+                pos = cache["pos"]
+                positions = jnp.full((b, 1), pos)
+            else:
+                positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+            if cfg.pos_emb == "rope":
+                q = layers.apply_rope(q, positions, cfg.rope_theta)
+                k = layers.apply_rope(k, positions, cfg.rope_theta)
 
-        new_cache = None
-        if not decode:
-            out = self._attend_train(q, k, v, kind, scale)
-            if plan.mode == "prefill":
-                new_cache = self._prefill_cache(k, v, kind, s)
-        else:
-            out, new_cache = self._attend_decode(q, k, v, cache, kind, scale)
+            new_cache = None
+            if not decode:
+                out = self._attend_train(q, k, v, kind, scale)
+                if plan.mode == "prefill":
+                    new_cache = self._prefill_cache(k, v, kind, s)
+            else:
+                out, new_cache = self._attend_decode(q, k, v, cache, kind,
+                                                     scale)
         out = out.reshape(b, s, nq * hd)
-        out = jnp.einsum("bsk,kd->bsd", out, p["wo"],
-                         preferred_element_type=jnp.float32).astype(x.dtype)
+        with jax.named_scope("attn_proj"):
+            out = jnp.einsum("bsk,kd->bsd", out, p["wo"],
+                             preferred_element_type=jnp.float32
+                             ).astype(x.dtype)
         return out, new_cache
 
     def _attend_train(self, q, k, v, kind, scale):
@@ -396,8 +404,9 @@ class Model:
             out, new_cache = self._attention_mixer(
                 p, lora_p, cache, x, kind, adapter_idx)
             x = plan.constrain(x + out)
-            h = layers.apply_norm(cfg.norm, p["norm2"], x)
-            f, aux = self._ffn(p, h)
+            with jax.named_scope("mlp"):
+                h = layers.apply_norm(cfg.norm, p["norm2"], x)
+                f, aux = self._ffn(p, h)
             x = plan.constrain(x + f)
             return x, new_cache, aux
         if kind == "ssd":
@@ -572,14 +581,17 @@ class Model:
     def decode_step(self, params, lora, cache, tokens, adapter_idx=None):
         """tokens: (B, 1). Returns (logits (B, V), new cache)."""
         cfg, plan = self.cfg, self.plan
-        x = layers.embed_tokens(params["embed"], tokens)
-        if cfg.pos_emb == "sinusoidal":
-            pe = layers.sinusoidal_pos_emb(cache["pos"][None, None], cfg.d_model)
-            x = x + pe.astype(x.dtype)
-        x = plan.constrain(x)
+        with jax.named_scope("embed"):
+            x = layers.embed_tokens(params["embed"], tokens)
+            if cfg.pos_emb == "sinusoidal":
+                pe = layers.sinusoidal_pos_emb(cache["pos"][None, None],
+                                               cfg.d_model)
+                x = x + pe.astype(x.dtype)
+            x = plan.constrain(x)
         x, new_segs, _ = self._run_segments(params, lora, cache, x, adapter_idx)
-        x = layers.apply_norm(cfg.norm, params["final_norm"], x)
-        logits = layers.unembed(params["embed"], x, cfg.logit_softcap)
+        with jax.named_scope("head"):
+            x = layers.apply_norm(cfg.norm, params["final_norm"], x)
+            logits = layers.unembed(params["embed"], x, cfg.logit_softcap)
         new_cache = {"pos": cache["pos"] + 1, "segments": new_segs}
         return logits[:, 0], new_cache
 

@@ -29,6 +29,7 @@ import dataclasses
 import math
 from typing import Callable, Dict, List, Optional
 
+from ..tracing import NULL_TRACER
 from .adapter_cache import AdapterSlotCache
 from .executor import StepTiming
 from .kv_cache import PagedKVCache
@@ -59,19 +60,15 @@ class EngineConfig:
     prefix_cache: bool = False
 
 
-@dataclasses.dataclass
-class StepTrace:
-    t: float
-    n_running: int
-    n_waiting: int
-    kv_used: float
-    lat: float
-
-
 class ServingEngine:
-    def __init__(self, cfg: EngineConfig, executor):
+    def __init__(self, cfg: EngineConfig, executor, tracer=None):
         self.cfg = cfg
         self.executor = executor
+        # one tracer per engine (repro.tracing), shared with its scheduler
+        # and with an executor that records spans; it only ever records
+        self.tracer = tracer if tracer is not None else NULL_TRACER
+        if hasattr(executor, "tracer"):
+            executor.tracer = self.tracer
         self.kv = PagedKVCache(cfg.kv_capacity_tokens, cfg.block_size)
         if cfg.dynamic_slots:
             def reserve(uid: int, dry: bool = False) -> bool:
@@ -93,8 +90,7 @@ class ServingEngine:
             SharedPrefixCache(self.kv) if cfg.prefix_cache else None
         self.scheduler = Scheduler(self.kv, self.adapters, cfg.max_running,
                                    policy=cfg.sched_policy,
-                                   prefix=self.prefix)
-        self.trace: List[StepTrace] = []
+                                   prefix=self.prefix, tracer=self.tracer)
         # streaming hook: called as ``on_token(req, t)`` for every token
         # the step loop generates (the async gateway fans these out to
         # per-request SSE streams).  None = no overhead on the hot loop.
@@ -134,9 +130,11 @@ class ServingEngine:
         self._pending = sorted(rest + list(requests), key=lambda r: r.arrival)
         self._next = 0
         self._accepted.extend(requests)
+        for r in requests:
+            self.tracer.begin("serve.queued", r.uid)
 
     def run_until(self, t_end: Optional[float] = None,
-                  record_trace: bool = False, strict: bool = False) -> None:
+                  strict: bool = False) -> None:
         """Advance the continuous-batching loop until the clock reaches
         ``t_end`` (None = run the submitted stream to completion).
 
@@ -145,9 +143,14 @@ class ServingEngine:
         replica idle *this* epoch is still at ``t_end`` when the next
         epoch submits more work.  Non-strict mode reproduces the original
         single-shot ``run()`` semantics exactly.
+
+        Each iteration that schedules is a ``serve.step`` span of the
+        engine's tracer, with children ``serve.schedule``,
+        ``serve.execute`` and ``serve.tokens``.
         """
         if self.halted:
             return
+        tracer = self.tracer
         while self._iters < self.cfg.max_steps:
             self._iters += 1
             t = self.clock
@@ -166,48 +169,56 @@ class ServingEngine:
                     self._pending[self._next].arrival <= t:
                 self.scheduler.add([self._pending[self._next]])
                 self._next += 1
-            plan = self.scheduler.schedule(t)
-            if not plan.running:
-                # blocked (e.g. waiting requests that cannot be admitted yet)
-                if self._next < len(self._pending):
-                    nxt = self._pending[self._next].arrival
-                    if strict and t_end is not None and nxt >= t_end:
-                        self.clock = max(self.clock, min(nxt, t_end))
-                        return
-                    self.clock = max(t, nxt)
-                    continue
+            with tracer.span("serve.step"):
+                with tracer.span("serve.schedule"):
+                    plan = self.scheduler.schedule(t)
+                if not plan.running:
+                    # blocked (e.g. waiting requests that cannot be
+                    # admitted yet)
+                    if self._next < len(self._pending):
+                        nxt = self._pending[self._next].arrival
+                        if strict and t_end is not None and nxt >= t_end:
+                            self.clock = max(self.clock, min(nxt, t_end))
+                            return
+                        self.clock = max(t, nxt)
+                        continue
+                    self.clock = t
+                    return
+                with tracer.span("serve.execute"):
+                    timing: StepTiming = self.executor.step(
+                        plan, self.scheduler.n_waiting)
+                total = timing.total
+                # guarded multiply: float * 1.0 is an identity but the
+                # guard keeps the healthy path free of any fp op (bitwise
+                # pinning)
+                if self.slow_factor != 1.0:
+                    total *= self.slow_factor
+                t += total
+                self.busy_time += total
+                self.n_exec_steps += 1
+                self.n_tokens_out += len(plan.running)
+                self._max_kv = max(self._max_kv, self.kv.used_fraction)
+                tracer.count("steps")
+                tracer.count("rows_decoded", len(plan.running))
+                tracer.count("admitted", len(plan.admitted))
+                tracer.count("preempted", len(plan.preempted))
+                tracer.count("cold_loads", len(plan.cold_loads))
+                # plan.running is already a snapshot; finish() mutates only
+                # the scheduler's own list, so no per-step defensive copy
+                # is needed
+                on_token = self.on_token
+                with tracer.span("serve.tokens"):
+                    for req in plan.running:
+                        req.generated += 1
+                        req.token_times.append(t)
+                        if req.first_token_at is None:
+                            req.first_token_at = t
+                        if req.done:
+                            req.finished_at = t
+                            self.scheduler.finish(req)
+                        if on_token is not None:
+                            on_token(req, t)
                 self.clock = t
-                return
-            timing: StepTiming = self.executor.step(
-                plan, self.scheduler.n_waiting)
-            total = timing.total
-            # guarded multiply: float * 1.0 is an identity but the guard
-            # keeps the healthy path free of any fp op (bitwise pinning)
-            if self.slow_factor != 1.0:
-                total *= self.slow_factor
-            t += total
-            self.busy_time += total
-            self.n_exec_steps += 1
-            self.n_tokens_out += len(plan.running)
-            self._max_kv = max(self._max_kv, self.kv.used_fraction)
-            if record_trace:
-                self.trace.append(StepTrace(
-                    t, len(plan.running), self.scheduler.n_waiting,
-                    self.kv.used_fraction, total))
-            # plan.running is already a snapshot; finish() mutates only the
-            # scheduler's own list, so no per-step defensive copy is needed
-            on_token = self.on_token
-            for req in plan.running:
-                req.generated += 1
-                req.token_times.append(t)
-                if req.first_token_at is None:
-                    req.first_token_at = t
-                if req.done:
-                    req.finished_at = t
-                    self.scheduler.finish(req)
-                if on_token is not None:
-                    on_token(req, t)
-            self.clock = t
 
     @property
     def queue_depth(self) -> int:
@@ -353,12 +364,11 @@ class ServingEngine:
         return found
 
     # ------------------------------------------------------------------ #
-    def run(self, requests: List[Request], horizon: Optional[float] = None,
-            record_trace: bool = False) -> ServingMetrics:
+    def run(self, requests: List[Request],
+            horizon: Optional[float] = None) -> ServingMetrics:
         """Single-shot: submit the whole stream, run to horizon/completion,
         summarize.  Identical semantics to the pre-resumable engine."""
         self.reset_stream()
         self.submit(requests)
-        self.run_until(horizon if horizon is not None else math.inf,
-                       record_trace=record_trace)
+        self.run_until(horizon if horizon is not None else math.inf)
         return self.finalize()

@@ -20,6 +20,7 @@ from typing import Dict, Optional
 
 import numpy as np
 
+from ..tracing import NULL_TRACER
 from .scheduler import StepPlan
 
 
@@ -103,6 +104,11 @@ class JaxExecutor:
 
     Uses padded static batch shapes (requests packed into a fixed-capacity
     batch with an active mask) so every step hits the same jit cache entry.
+
+    ``step`` records three spans on ``tracer`` (the engine hands it its
+    own): ``serve.prepare`` (the adapter ids, to the device),
+    ``serve.dispatch`` (the jitted call until it returns) and
+    ``serve.sync`` (the wait for its logits).
     """
 
     def __init__(self, model, params, lora, max_batch: int = 8,
@@ -118,6 +124,7 @@ class JaxExecutor:
             max_batch, cache_len)
         self.tokens = jnp.zeros((max_batch, 1), jnp.int32)
         self.decode = jax.jit(model.decode_step)
+        self.tracer = NULL_TRACER
         self._slot_of: Dict[int, int] = {}
         # warmup
         idx = jnp.zeros((max_batch,), jnp.int32)
@@ -125,18 +132,20 @@ class JaxExecutor:
         jax.block_until_ready(out[0])
 
     def step(self, plan: StepPlan, n_waiting: int) -> StepTiming:
-        jnp = self.jnp
-        t0 = time.perf_counter()
-        idx = np.zeros((self.max_batch,), np.int32)
-        for i, req in enumerate(plan.running[: self.max_batch]):
-            idx[i] = req.adapter % max(self.lora_count(), 1)
-        t_sched = time.perf_counter() - t0
-
-        t1 = time.perf_counter()
-        logits, self.cache = self.decode(
-            self.params, self.lora, self.cache, self.tokens,
-            jnp.asarray(idx))
-        self.jax.block_until_ready(logits)
+        jnp, tracer = self.jnp, self.tracer
+        with tracer.span("serve.prepare"):
+            t0 = time.perf_counter()
+            idx = np.zeros((self.max_batch,), np.int32)
+            for i, req in enumerate(plan.running[: self.max_batch]):
+                idx[i] = req.adapter % max(self.lora_count(), 1)
+            t_sched = time.perf_counter() - t0
+            t1 = time.perf_counter()
+            idx = jnp.asarray(idx)
+        with tracer.span("serve.dispatch"):
+            logits, self.cache = self.decode(
+                self.params, self.lora, self.cache, self.tokens, idx)
+        with tracer.span("serve.sync"):
+            self.jax.block_until_ready(logits)
         # emulate prefill cost: extra decode steps pro-rated by tokens
         t_model = time.perf_counter() - t1
         if plan.prefill_tokens:

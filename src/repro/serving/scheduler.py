@@ -17,6 +17,7 @@ import dataclasses
 from collections import deque
 from typing import Deque, List, Optional, Set, Union
 
+from ..tracing import NULL_TRACER
 from .adapter_cache import AdapterSlotCache
 from .kv_cache import PagedKVCache
 from .policy import (SchedulingPolicy, SchedView, make_sched_policy,
@@ -70,8 +71,10 @@ class Scheduler:
     def __init__(self, kv: PagedKVCache, adapters: AdapterSlotCache,
                  max_running: int = 256,
                  policy: Union[str, SchedulingPolicy] = "fcfs",
-                 prefix: Optional[SharedPrefixCache] = None):
+                 prefix: Optional[SharedPrefixCache] = None,
+                 tracer=NULL_TRACER):
         self.kv = kv
+        self.tracer = tracer
         self.adapters = adapters
         self.prefix = prefix
         self.max_running = max_running
@@ -132,10 +135,14 @@ class Scheduler:
             self.prefix.release(victim.uid)
         victim.n_preemptions += 1
         self.waiting.appendleft(victim)
+        self.tracer.begin("serve.queued", victim.uid)
         return victim
 
     # ------------------------------------------------------------------ #
     def schedule(self, now: float) -> StepPlan:
+        """One step's plan.  The tracer hears of each blocking branch (a
+        slot skip, a KV stop, a full batch) and of each admission."""
+        tracer = self.tracer
         admitted: List[Request] = []
         preempted: List[Request] = []
         cold_loads: List[int] = []
@@ -176,10 +183,15 @@ class Scheduler:
         # policy's ordering work entirely (mirrors the fast path's guard)
         candidates = self.waiting if self.waiting and \
             len(self.running) < self.max_running else ()
+        if self.waiting and not candidates:
+            tracer.count("rows_full")
+            tracer.note("rows_full")
         if candidates and self.policy.name != "fcfs":
             candidates = self.policy.order(candidates, self._view, now)
         for req in candidates:
             if len(self.running) >= self.max_running:
+                tracer.count("rows_full")
+                tracer.note("rows_full")
                 break
             if req.uid in just_preempted:
                 continue
@@ -221,8 +233,12 @@ class Scheduler:
                     verdict = "stop"
                 break
             if verdict == "skip":
+                tracer.count("slot_skips")
+                tracer.note("slot_skip", req.uid)
                 continue
             if verdict == "stop":
+                tracer.count("kv_stops")
+                tracer.note("kv_stop")
                 break
             if self.adapters.load(req.adapter, now):
                 cold_loads.append(req.adapter)
@@ -234,6 +250,7 @@ class Scheduler:
                              req.context_len + 1 - covered - want_insert)
             covered_total += covered
             req.admitted_at = now
+            tracer.end("serve.queued", req.uid)
             self._append_running(req)
             admitted.append(req)
             admitted_uids.add(req.uid)
