@@ -128,23 +128,27 @@ def main() -> int:
 
     m = serve.serve(args, ex)
     steps = int(ex.cache["pos"])
-    cache_len = ex.cache["segments"][0]["blocks"][0]["k"].shape[2]
+    cache_len = ex.cache["segments"][0]["blocks"][0]["k"].shape[3]
     print(f"served: finished={m.n_finished} decode_steps={steps} "
           f"cache_len={cache_len}")
     check(m.n_finished >= 1, "no request finished")
     check(steps < cache_len, f"{steps} decode steps overran the "
           f"{cache_len}-slot KV cache")
 
-    # -- the decode step at the served cache state ----------------------
+    # -- the decode step from the served cache state ---------------------
     idx = jnp.arange(ex.max_batch, dtype=jnp.int32) % ex.lora_count()
-    step_args = (ex.params, ex.lora, ex.cache, ex.tokens, idx)
-    hlo = ex.decode.lower(*step_args).compile().as_text()
+    hlo = ex.decode.lower(ex.params, ex.lora, ex.cache, ex.tokens,
+                          idx).compile().as_text()
     check("tpu_custom_call" in hlo,
           "compiled decode step has no tpu_custom_call (Pallas LoRA kernel)")
+    check(steps + TIMED_STEPS < cache_len, f"{steps} + {TIMED_STEPS} decode "
+          f"steps would overrun the {cache_len}-slot KV cache")
     times = []
     for _ in range(TIMED_STEPS):
         t0 = time.perf_counter()
-        logits, _ = ex.decode(*step_args)
+        # the step donates its cache: carry the returned one to the next
+        logits, ex.cache = ex.decode(ex.params, ex.lora, ex.cache,
+                                     ex.tokens, idx)
         jax.block_until_ready(logits)
         times.append(time.perf_counter() - t0)
     check(logits.shape[0] == ex.max_batch

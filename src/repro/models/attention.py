@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 NEG_INF = -1.0e30
 
@@ -241,67 +242,83 @@ def quantize_kv(x):
     return q, scale
 
 
-def decode_update_cache(cache, new, pos, my, s_loc):
-    """Masked append of `new` (B, KV, ...) into the local slice
-    (B, S_loc, KV, ...) — works for values (4-d) and scales (3-d)."""
+def decode_update_cache(stack, new, layer, pos, my, s_loc):
+    """Write `new` (B, KV, ...) at position `pos` of layer `layer` of the
+    local stacked slice (R, B, KV, S_loc, ...), in place: values (5-d) and
+    scales (4-d).  A position outside this shard's range keeps the old row,
+    so the guard reads and writes one (B, KV, ...) row, never a layer."""
     with jax.named_scope("kv_update"):
         local = pos - my * s_loc
         ok = (local >= 0) & (local < s_loc)
         idx = jnp.clip(local, 0, s_loc - 1)
-        start = (0, idx) + (0,) * (cache.ndim - 2)
-        upd = jax.lax.dynamic_update_slice(
-            cache, new[:, None].astype(cache.dtype), start)
-        return jnp.where(ok, upd, cache)
+        start = (layer, 0, 0, idx) + (0,) * (stack.ndim - 4)
+        old = jax.lax.dynamic_slice(
+            stack, start, (1, *stack.shape[1:3], 1, *stack.shape[4:]))
+        row = jnp.where(ok, new[None, :, :, None].astype(stack.dtype), old)
+        stack = jax.lax.dynamic_update_slice(stack, row, start)
+        # keep the stack row-major, the layout in which each layer is the
+        # operand attention's dots read.  Left free, the TPU compiler lays
+        # the stack out so that the row fills whole tiles, and then copies
+        # the whole cache into and out of that layout every step, and each
+        # layer back for the dots.
+        return with_layout_constraint(stack, Layout(tuple(range(stack.ndim))))
 
 
-def decode_attention_sharded(q, k_cache, v_cache, new_k, new_v, pos, *,
+def decode_attention_sharded(q, k_cache, v_cache, new_k, new_v, pos, layer, *,
                              axis_name: str, n_shards: int, scale: float,
                              k_scale=None, v_scale=None):
     """Per-device body.
 
-    q: (B, H, D) replicated over `axis_name`; caches: (B, S_loc, KV, D) local
-    slice; new_k/new_v: (B, KV, D) replicated; pos: scalar index being written.
-    With ``k_scale``/``v_scale`` (B, S_loc, KV) the caches are int8 and
-    dequantized on the fly (scores scale by k_scale; p scales by v_scale).
+    q: (B, H, D) replicated over `axis_name`; caches: (R, B, KV, S_loc, D)
+    local slice of every layer of a segment's block; new_k/new_v: (B, KV, D)
+    replicated; pos: scalar index being written; layer: the index into R of
+    this layer.  With ``k_scale``/``v_scale`` (R, B, KV, S_loc) the caches
+    are int8 and dequantized on the fly (scores scale by k_scale; p scales
+    by v_scale).  The new row is written into the stacks in place, and
+    attention reads layer `layer` of the updated stacks.
     Returns ((B, H, D) out, updated caches [, updated scales]).
     """
     b, h, d = q.shape
     kvh = k_cache.shape[2]
     g = h // kvh
-    s_loc = k_cache.shape[1]
+    s_loc = k_cache.shape[3]
     my = jax.lax.axis_index(axis_name) if n_shards > 1 else 0
     quant = k_scale is not None
 
     if quant:
         nk, nks = quantize_kv(new_k)
         nv, nvs = quantize_kv(new_v)
-        k_cache = decode_update_cache(k_cache, nk, pos, my, s_loc)
-        v_cache = decode_update_cache(v_cache, nv, pos, my, s_loc)
-        k_scale = decode_update_cache(k_scale, nks, pos, my, s_loc)
-        v_scale = decode_update_cache(v_scale, nvs, pos, my, s_loc)
+        k_cache = decode_update_cache(k_cache, nk, layer, pos, my, s_loc)
+        v_cache = decode_update_cache(v_cache, nv, layer, pos, my, s_loc)
+        k_scale = decode_update_cache(k_scale, nks, layer, pos, my, s_loc)
+        v_scale = decode_update_cache(v_scale, nvs, layer, pos, my, s_loc)
     else:
-        k_cache = decode_update_cache(k_cache, new_k, pos, my, s_loc)
-        v_cache = decode_update_cache(v_cache, new_v, pos, my, s_loc)
+        k_cache = decode_update_cache(k_cache, new_k, layer, pos, my, s_loc)
+        v_cache = decode_update_cache(v_cache, new_v, layer, pos, my, s_loc)
 
+    def read(stack):
+        return jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+
+    kc, vc = read(k_cache), read(v_cache)
     kv_pos = my * s_loc + jnp.arange(s_loc)
     mask = (kv_pos <= pos)[None, None, None, :]                # (1,1,1,S)
     qs = q.reshape(b, kvh, g, d)
-    kk = k_cache.astype(jnp.bfloat16) if quant else k_cache
-    s = jnp.einsum("bkgd,bskd->bkgs", qs, kk,
+    kk = kc.astype(jnp.bfloat16) if quant else kc
+    s = jnp.einsum("bkgd,bksd->bkgs", qs, kk,
                    preferred_element_type=jnp.float32) * scale
     if quant:
-        s = s * k_scale.astype(jnp.float32).transpose(0, 2, 1)[:, :, None]
+        s = s * read(k_scale).astype(jnp.float32)[:, :, None]
     s = jnp.where(mask, s, NEG_INF)
     m = jnp.max(s, axis=-1)
     p = jnp.where(mask, jnp.exp(s - m[..., None]), 0.0)
     lse = jnp.sum(p, axis=-1)
     if quant:
-        pv = p * v_scale.astype(jnp.float32).transpose(0, 2, 1)[:, :, None]
-        acc = jnp.einsum("bkgs,bskd->bkgd", pv.astype(jnp.bfloat16),
-                         v_cache.astype(jnp.bfloat16),
+        pv = p * read(v_scale).astype(jnp.float32)[:, :, None]
+        acc = jnp.einsum("bkgs,bksd->bkgd", pv.astype(jnp.bfloat16),
+                         vc.astype(jnp.bfloat16),
                          preferred_element_type=jnp.float32)
     else:
-        acc = jnp.einsum("bkgs,bskd->bkgd", p, v_cache.astype(jnp.float32),
+        acc = jnp.einsum("bkgs,bksd->bkgd", p, vc.astype(jnp.float32),
                          preferred_element_type=jnp.float32)
     if n_shards > 1:
         m_g = jax.lax.pmax(m, axis_name)

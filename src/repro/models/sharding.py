@@ -187,10 +187,10 @@ class ShardingPlan:
         dp = self.dp()
         w = self.width_axis or None
         cache_seq = self.cache_seq_axes if self.cache_seq_axes else None
-        if name in ("k", "v"):             # (R, B, S, KV, D) global layers
-            return P(None, dp, cache_seq, None, None)
-        if name in ("k_scale", "v_scale"):  # (R, B, S, KV) int8-KV scales
-            return P(None, dp, cache_seq, None)
+        if name in ("k", "v"):             # (R, B, KV, S, D) global layers
+            return P(None, dp, None, cache_seq, None)
+        if name in ("k_scale", "v_scale"):  # (R, B, KV, S) int8-KV scales
+            return P(None, dp, None, cache_seq)
         if name in ("k_loc", "v_loc"):     # (R, B, W, KV, D) rolling
             return P(None, dp, None, None, None)
         if name in ("conv_x", "conv"):     # (R, B, cw-1, C@width)

@@ -152,18 +152,20 @@ class Model:
         dt = cfg.jnp_dtype
         stack = (repeats, batch)
         if kind == "global":
+            # (R, B, KV, S, D): each layer is the (B, KV, S, D) operand that
+            # decode attention's dots read, one contiguous block of the stack
             hd, nkv = cfg.resolved_head_dim, cfg.n_kv_heads
             if self.plan.kv_quant:
                 return {
-                    "k": jnp.zeros((*stack, cache_len, nkv, hd), jnp.int8),
-                    "v": jnp.zeros((*stack, cache_len, nkv, hd), jnp.int8),
-                    "k_scale": jnp.zeros((*stack, cache_len, nkv),
+                    "k": jnp.zeros((*stack, nkv, cache_len, hd), jnp.int8),
+                    "v": jnp.zeros((*stack, nkv, cache_len, hd), jnp.int8),
+                    "k_scale": jnp.zeros((*stack, nkv, cache_len),
                                          jnp.float16),
-                    "v_scale": jnp.zeros((*stack, cache_len, nkv),
+                    "v_scale": jnp.zeros((*stack, nkv, cache_len),
                                          jnp.float16),
                 }
-            return {"k": jnp.zeros((*stack, cache_len, nkv, hd), dt),
-                    "v": jnp.zeros((*stack, cache_len, nkv, hd), dt)}
+            return {"k": jnp.zeros((*stack, nkv, cache_len, hd), dt),
+                    "v": jnp.zeros((*stack, nkv, cache_len, hd), dt)}
         if kind == "local":
             hd, nkv = cfg.resolved_head_dim, cfg.n_kv_heads
             w = min(cfg.local_window, cache_len)
@@ -293,13 +295,13 @@ class Model:
         if kind == "global":
             if self.plan.kv_quant:
                 # quantize over D per (token, head): vmap the (B, KV, D)
-                # quantizer over the seq axis
+                # quantizer over the seq axis, into the cache's (B, KV, S, D)
                 kq, ks = jax.vmap(attention.quantize_kv, in_axes=1,
-                                  out_axes=1)(k)
+                                  out_axes=2)(k)
                 vq, vs = jax.vmap(attention.quantize_kv, in_axes=1,
-                                  out_axes=1)(v)
+                                  out_axes=2)(v)
                 return {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-            return {"k": k, "v": v}
+            return {"k": k.swapaxes(1, 2), "v": v.swapaxes(1, 2)}
         w = min(cfg.local_window, s)
         shift = (s - w) % max(w, 1)
 
@@ -319,9 +321,10 @@ class Model:
             return out[:, None], {"k_loc": nk, "v_loc": nv}
         n = plan.n_cache
         quant = plan.kv_quant
+        layer = cache["layer"]
         if n == 1:
             outs = attention.decode_attention_sharded(
-                q1, cache["k"], cache["v"], k1, v1, pos,
+                q1, cache["k"], cache["v"], k1, v1, pos, layer,
                 axis_name="", n_shards=1, scale=scale,
                 k_scale=cache.get("k_scale") if quant else None,
                 v_scale=cache.get("v_scale") if quant else None)
@@ -330,24 +333,24 @@ class Model:
         axis = axes if len(axes) > 1 else axes[0]
         dp = plan.dp()
         qspec = P(dp, None, None)
-        cspec = P(dp, axes, None, None)
-        sspec = P(dp, axes, None)
+        cspec = plan.cache_spec(("k",), cache["k"].shape)
+        sspec = plan.cache_spec(("k_scale",), cache["k"].shape[:-1])
         body = functools.partial(
             attention.decode_attention_sharded, axis_name=axis,
             n_shards=n, scale=scale)
-        in_specs = [qspec, cspec, cspec, qspec, qspec, P()]
+        in_specs = [qspec, cspec, cspec, qspec, qspec, P(), P()]
         out_specs = [qspec, cspec, cspec]
         args = [plan.constrain(q1, qspec), cache["k"], cache["v"],
-                plan.constrain(k1, qspec), plan.constrain(v1, qspec), pos]
+                plan.constrain(k1, qspec), plan.constrain(v1, qspec), pos,
+                layer]
         if quant:
-            body = functools.partial(body)
             in_specs += [sspec, sspec]
             out_specs += [sspec, sspec]
             args += [cache["k_scale"], cache["v_scale"]]
 
-            def body(q, kc, vc, nk, nv, p, ks, vs):  # noqa: F811
+            def body(q, kc, vc, nk, nv, p, r, ks, vs):  # noqa: F811
                 return attention.decode_attention_sharded(
-                    q, kc, vc, nk, nv, p, axis_name=axis, n_shards=n,
+                    q, kc, vc, nk, nv, p, r, axis_name=axis, n_shards=n,
                     scale=scale, k_scale=ks, v_scale=vs)
         outs = jax.shard_map(body, mesh=plan.mesh,
                              in_specs=tuple(in_specs),
@@ -443,56 +446,74 @@ class Model:
     # segment scan
     # ------------------------------------------------------------------ #
     def _run_segments(self, params, lora, cache, x, adapter_idx):
-        """Returns (x, new_cache_segments_or_None, aux)."""
+        """Returns (x, new_cache_segments_or_None, aux).
+
+        In decode, each global-attention block's stacked cache, (R, B, KV,
+        S, ...) over the segment's R repeats, rides in the loop's carry:
+        layer r writes its one new position into the stack in place and
+        attention reads layer r from it, so no layer's cache is sliced out
+        or restacked.  The other kinds' small states go through the loop as
+        per-layer inputs and outputs."""
         plan = self.plan
-        aux_total = 0.0
         new_segs = [] if cache is not None or plan.mode == "prefill" else None
 
         aux_total = jnp.zeros((), jnp.float32)
         for si, seg in enumerate(self.segments):
             nk = len(seg.kinds)
+            carried = tuple(cache is not None and kind == "global"
+                            for kind in seg.kinds)
 
-            def body(carry, xs, seg=seg, nk=nk):
-                xx, aux = carry
+            def body(carry, xs, seg=seg, nk=nk, carried=carried):
+                xx, aux, stacks = carry
                 pb = xs["p"]
                 lb = xs["l"] if "l" in xs else (None,) * nk
                 cb = xs["c"] if "c" in xs else (None,) * nk
-                new_cb = []
+                new_cb, new_stacks = [], list(stacks)
                 for i, kind in enumerate(seg.kinds):
-                    ci = cb[i]
+                    ci = dict(stacks[i], layer=xs["r"]) if carried[i] \
+                        else cb[i]
                     if isinstance(ci, dict):
-                        ci = dict(ci)
-                        ci["pos"] = cache["pos"]
+                        ci = dict(ci, pos=cache["pos"])
                     xx, nc, a = self._apply_block(
                         kind, pb[i], lb[i], ci, xx, adapter_idx)
                     aux = aux + a
+                    if carried[i]:
+                        new_stacks[i], nc = nc, None
                     new_cb.append(nc if nc is not None else 0)
-                return (xx, aux), tuple(new_cb)
+                return (xx, aux, tuple(new_stacks)), tuple(new_cb)
 
             xs = {"p": params["segments"][si]["blocks"]}
             if lora is not None:
                 xs["l"] = lora["segments"][si]["blocks"]
+            stacks = (None,) * nk
             if cache is not None:
-                xs["c"] = cache["segments"][si]["blocks"]
+                blocks = cache["segments"][si]["blocks"]
+                stacks = tuple(c if keep else None
+                               for c, keep in zip(blocks, carried))
+                xs["c"] = tuple(None if keep else c
+                                for c, keep in zip(blocks, carried))
+                xs["r"] = jnp.arange(seg.repeats)
 
             if plan.remat:
                 body = jax.checkpoint(body)
 
+            carry = (x, aux_total, stacks)
             if plan.unroll:
-                carry = (x, aux_total)
                 ys = []
                 for r in range(seg.repeats):
                     xr = jax.tree.map(lambda a: a[r], xs)
                     carry, y = body(carry, xr)
                     ys.append(y)
-                x, aux_total = carry
                 ys = (jax.tree.map(lambda *a: jnp.stack(a), *ys)
                       if new_segs is not None else None)
             else:
-                (x, aux_total), ys = jax.lax.scan(body, (x, aux_total), xs)
+                carry, ys = jax.lax.scan(body, carry, xs)
+            x, aux_total, stacks = carry
 
             if new_segs is not None:
-                new_segs.append({"blocks": ys})
+                new_segs.append({"blocks": tuple(
+                    st if keep else y
+                    for st, y, keep in zip(stacks, ys, carried))})
         return x, new_segs, aux_total
 
     # ------------------------------------------------------------------ #
@@ -613,9 +634,10 @@ def pad_cache(cache, extra: int):
             nb = {}
             for k, v in bd.items():
                 if k in ("k", "v", "k_scale", "v_scale"):
-                    pad = jnp.zeros(v.shape[:2] + (extra,) + v.shape[3:],
+                    # the sequence axis of (R, B, KV, S[, D])
+                    pad = jnp.zeros(v.shape[:3] + (extra,) + v.shape[4:],
                                     v.dtype)
-                    nb[k] = jnp.concatenate([v, pad], axis=2)
+                    nb[k] = jnp.concatenate([v, pad], axis=3)
                 else:
                     nb[k] = v
             blocks.append(nb)

@@ -120,16 +120,22 @@ class JaxExecutor:
         self.params = params
         self.lora = lora
         self.max_batch = max_batch
-        self.cache = jax.jit(model.init_cache, static_argnums=(0, 1))(
-            max_batch, cache_len)
+        init_cache = jax.jit(model.init_cache, static_argnums=(0, 1))
         self.tokens = jnp.zeros((max_batch, 1), jnp.int32)
-        self.decode = jax.jit(model.decode_step)
+        # the step donates its cache: the caller's buffer becomes the
+        # returned cache, which the step writes in place
+        self.decode = jax.jit(model.decode_step, donate_argnums=(2,))
         self.tracer = NULL_TRACER
         self._slot_of: Dict[int, int] = {}
-        # warmup
+        # warm up on a cache of its own, so that the served one starts at
+        # position 0, all zeros; drop it first, so that two caches are
+        # never held at once
         idx = jnp.zeros((max_batch,), jnp.int32)
-        out = self.decode(params, lora, self.cache, self.tokens, idx)
+        out = self.decode(params, lora, init_cache(max_batch, cache_len),
+                          self.tokens, idx)
         jax.block_until_ready(out[0])
+        del out
+        self.cache = init_cache(max_batch, cache_len)
 
     def step(self, plan: StepPlan, n_waiting: int) -> StepTiming:
         jnp, tracer = self.jnp, self.tracer

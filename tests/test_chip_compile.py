@@ -20,6 +20,7 @@ from repro.kernels import ops
 from repro.kernels.bgmv import bgmv
 from repro.kernels.sgmv import sgmv
 from repro.models import Model, ShardingPlan
+from tests.hlo_ops import written
 
 PHI4 = get_config("phi4-mini-3.8b")
 D = PHI4.d_model
@@ -84,18 +85,22 @@ def test_sgmv_compiles_at_prefill_size(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_phi4_decode_step_compiles_with_pallas_lora(one_chip, monkeypatch):
+def _phi4_decode_args(one_chip, monkeypatch):
     # jax.default_backend() is the CPU here: steer the model's LoRA
     # dispatch onto the TPU branch the chip takes
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     model = Model(PHI4, ShardingPlan(mode="decode"))
     key = jax.random.PRNGKey(0)
-    args = _on(one_chip, (
+    return model, _on(one_chip, (
         jax.eval_shape(model.init, key),
         jax.eval_shape(lambda k: model.init_lora(k, N_SLOTS, RANK), key),
         jax.eval_shape(lambda: model.init_cache(BATCH, CACHE_LEN)),
         jax.ShapeDtypeStruct((BATCH, 1), jnp.int32),
         jax.ShapeDtypeStruct((BATCH,), jnp.int32)))
+
+
+def test_phi4_decode_step_compiles_with_pallas_lora(one_chip, monkeypatch):
+    model, args = _phi4_decode_args(one_chip, monkeypatch)
     compiled = jax.jit(model.decode_step).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     # weights + KV cache + step temporaries fit a 16 GB v5e chip
@@ -103,3 +108,20 @@ def test_phi4_decode_step_compiles_with_pallas_lora(one_chip, monkeypatch):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < 16e9, used
+
+
+def test_phi4_donated_decode_step_updates_its_cache_in_place(one_chip,
+                                                             monkeypatch):
+    # jitted as JaxExecutor jits it: the cache, argument 2, is donated
+    model, args = _phi4_decode_args(one_chip, monkeypatch)
+    compiled = jax.jit(model.decode_step, donate_argnums=(2,)).lower(
+        *args).compile()
+    stack = args[2]["segments"][0]["blocks"][0]["k"]
+    kv_bytes = 2 * stack.size * stack.dtype.itemsize
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= kv_bytes, mem.alias_size_in_bytes
+    # no copy of the stacked cache, nor of one layer of it, as temporaries
+    assert mem.temp_size_in_bytes < kv_bytes / 4, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    assert written(text, stack.shape) == []
+    assert written(text, stack.shape[1:]) == []
