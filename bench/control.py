@@ -44,15 +44,15 @@ def readings(workload: str, seconds: float, seeds, control_seeds, *,
     for seed in seeds:
         out = run.execute(jax, prog, spec, seed, seconds, False,
                           time.perf_counter())
-        ref, gap, err = run.compare(seed, cfg, out)
+        ref, gap, err = run.compare(spec["arch"], seed, cfg, out)
         program[seed] = (float(gap.max()), float(err.max()))
         line = {"seed": seed, "program_gap": program[seed][0],
                 "program_err": program[seed][1],
                 "pairs": len(out["at"]), "decode_steps":
                 int(out["fed_idx"].shape[0])}
         if seed in control_seeds:
-            low = reference.logits(seed, cfg, out["vpad"], out["slots"],
-                                   out["rank"], out["fed_tok"],
+            low = reference.logits(spec["arch"], seed, cfg, out["vpad"],
+                                   out["slots"], out["rank"], out["fed_tok"],
                                    out["fed_idx"], out["at"], mode="fp8")
             control[seed] = (float(check.gaps(low, ref).max()),
                              float(check.rel_err(low, ref).max()))

@@ -15,12 +15,12 @@ median over the runs with ``rows - 0.25`` rows busy or more), divided by
 the mix's mean output tokens per request.  Above it the queue grows
 without end; a short window cannot show that growth, since a request is
 served for about ten seconds, but the plateau it reads well.  The knee is
-written, with the sweep, into the traffic files by hand.
+printed, and written with the sweep into ``bench/knees/<config>.<mix>.json``
+by hand.
 """
 from __future__ import annotations
 
 import argparse
-import copy
 import json
 import statistics
 import sys
@@ -47,15 +47,14 @@ def sweep(workload: str, seconds: float, capacity: float, shares,
     if why and require_chip:
         raise SystemExit(f"knee_sweep: {why}")
     run.compile_cache(jax, prog)
-    name, rows_n = spec["config"]["name"], spec["config"]["serving"]["batch"]
+    rows_n = spec["config"]["serving"]["batch"]
     mean_out = traffic.mean_output(spec["traffic"])
     rows = []
     for trace_seed in trace_seeds:
         for share in shares:
-            s = copy.deepcopy(spec)
-            s["traffic"].update(knee_req_s={name: capacity},
-                                rate_share_of_knee=share,
-                                trace_seed=trace_seed)
+            s = dict(spec, knee=capacity,
+                     traffic=dict(spec["traffic"], rate_share_of_knee=share,
+                                  trace_seed=trace_seed))
             out = run.execute(jax, prog, s, 1, seconds, False,
                               time.perf_counter())
             w0, w1 = out["win"]

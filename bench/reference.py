@@ -8,10 +8,8 @@ keeps one position for the whole batch).  So row ``b`` is a causal sequence
 of ``P`` tokens, and the reference runs it as one full forward pass, layer
 by layer and a few rows at a time, so that it fits beside nothing else.
 
-The layer follows the published decoder: RMSNorm, q/k/v projections with
-a LoRA delta ``x @ A[id] @ B[id]`` on q and v, rotary embedding
-(rotate-half, full head), causal grouped-query attention, output
-projection, residual, RMSNorm, SwiGLU MLP, residual; then the final
+The embedding, then each layer group of the architecture's module
+(``bench/arch/``) in order, through the module's ``layer``; then the final
 RMSNorm and the (tied or untied) unembedding.
 
 ``mode="fp8"`` is the control: every matmul's operands are rounded to
@@ -33,26 +31,26 @@ F8 = jnp.float8_e4m3fn
 F8_MAX = 448.0
 
 
-def _q8(x, axis):
+def q8(x, axis):
     """Round to float8 e4m3 with an absmax scale over ``axis``."""
     s = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / F8_MAX
     s = jnp.where(s > 0, s, 1.0)
     return (x / s).astype(F8).astype(jnp.float32) * s
 
 
-def _mm(a, w, fp8: bool):
+def mm(a, w, fp8: bool):
     """a (..., k) @ w (k, n) in float32."""
     if fp8:
-        a, w = _q8(a, -1), _q8(w, None)
+        a, w = q8(a, -1), q8(w, None)
     return jnp.einsum("...k,kn->...n", a, w, precision=HI)
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * scale
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x (P, H, D), pos (P,)."""
     d = x.shape[-1]
     inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
@@ -62,55 +60,28 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
 
 
-def _lora(h, a, b, ids, fp8):
+def lora_delta(h, a, b, ids, fp8):
     """h (P, d); a (N, d, r); b (N, r, o); ids (P,) -> (P, o)."""
     def one(acc, n):
-        y = _mm(_mm(h, a[n], fp8), b[n], fp8)
+        y = mm(mm(h, a[n], fp8), b[n], fp8)
         return acc + jnp.where((ids == n)[:, None], y, 0.0), None
     out = jnp.zeros((h.shape[0], b.shape[-1]), jnp.float32)
     return jax.lax.scan(one, out, jnp.arange(a.shape[0]))[0]
 
 
-def _row_layer(m, w, x, ids, fp8):
-    """One decoder layer over one row's whole sequence x (P, d)."""
-    p_len = x.shape[0]
-    hd, nq, nkv = m["head_dim"], m["n_heads"], m["n_kv_heads"]
-    eps = m["norm_eps"]
-    pos = jnp.arange(p_len)
-    h = _rms(x, w["norm1"], eps)
-    q = _mm(h, w["wq"], fp8) + _lora(h, w["a_q"], w["b_q"], ids, fp8)
-    k = _mm(h, w["wk"], fp8)
-    v = _mm(h, w["wv"], fp8) + _lora(h, w["a_v"], w["b_v"], ids, fp8)
-    q = _rope(q.reshape(p_len, nq, hd), pos, m["rope_theta"])
-    k = _rope(k.reshape(p_len, nkv, hd), pos, m["rope_theta"])
-    v = v.reshape(p_len, nkv, hd)
-    q = q.reshape(p_len, nkv, nq // nkv, hd)
-    if fp8:
-        q, k, v = _q8(q, -1), _q8(k, -1), _q8(v, -1)
-    s = jnp.einsum("qkgd,skd->kgqs", q, k, precision=HI) / np.sqrt(hd)
-    causal = pos[:, None] >= pos[None, :]
-    s = jnp.where(causal, s, -jnp.inf)
-    pr = jax.nn.softmax(s, axis=-1)
-    if fp8:
-        pr = _q8(pr, -1)
-    o = jnp.einsum("kgqs,skd->qkgd", pr, v, precision=HI)
-    x = x + _mm(o.reshape(p_len, nq * hd), w["wo"], fp8)
-    h = _rms(x, w["norm2"], eps)
-    f = jax.nn.silu(_mm(h, w["w_gate"], fp8)) * _mm(h, w["w_up"], fp8)
-    return x + _mm(f, w["w_down"], fp8)
-
-
-@functools.partial(jax.jit, static_argnames=("m_items", "fp8", "rows"))
-def _layer(sem, layer, x, ids, m_items, fp8, rows):
+@functools.partial(jax.jit, static_argnames=("arch", "group", "m_items",
+                                             "fp8", "rows"))
+def _layer(tensors, layer, x, ids, arch, group, m_items, fp8, rows):
     m = dict(m_items)
     w = {k: jax.lax.dynamic_index_in_dim(v, layer, keepdims=False)
          .astype(jnp.float32)
-         for k, v in {**sem["layers"], **sem["lora"]}.items()}
+         for k, v in {**tensors["layers"], **tensors["lora"]}.items()}
     b, p_len, d = x.shape
     xb = x.reshape(b // rows, rows, p_len, d)
     ib = ids.reshape(b // rows, rows, p_len)
     out = jax.lax.map(
-        lambda a: jax.vmap(lambda xx, ii: _row_layer(m, w, xx, ii, fp8))(*a),
+        lambda a: jax.vmap(
+            lambda xx, ii: arch.layer(m, group, w, xx, ii, fp8))(*a),
         (xb, ib))
     return out.reshape(b, p_len, d)
 
@@ -118,42 +89,36 @@ def _layer(sem, layer, x, ids, m_items, fp8, rows):
 @functools.partial(jax.jit, static_argnames=("m_items", "fp8", "vocab"))
 def _logits(sem, x, rows, cols, m_items, fp8, vocab):
     m = dict(m_items)
-    h = _rms(x[rows, cols], sem["final_norm"].astype(jnp.float32),
-             m["norm_eps"])
+    h = rms(x[rows, cols], sem["final_norm"].astype(jnp.float32),
+            m["norm_eps"])
     if "unembed" in sem:
         w = sem["unembed"][:, :vocab].astype(jnp.float32)
     else:
         w = sem["embed"][:vocab].astype(jnp.float32).T
-    return _mm(h, w, fp8)
+    return mm(h, w, fp8)
 
 
-def row_block(m: dict, batch: int) -> int:
-    """Rows per block: as many as keep one block's attention scores and
-    MLP activations near 1 GB in float32."""
-    rows = batch
-    while rows > 1 and rows * m["n_heads"] * 2048 * 2048 * 4 > 1e9:
-        rows //= 2
-    return max(rows, 1)
-
-
-def logits(seed: int, cfg: dict, vpad: int, slots: int, rank: int,
+def logits(arch, seed: int, cfg: dict, vpad: int, slots: int, rank: int,
            tokens: np.ndarray, idx: np.ndarray, at: list,
            mode: str = "f32") -> np.ndarray:
     """Reference logits (len(at), vocab) at the (step, row) pairs ``at``.
 
-    cfg: the configuration file; tokens, idx: (P, B) what the program was
-    fed at each decode step."""
+    arch: the configuration's module; cfg: the configuration file; tokens,
+    idx: (P, B) what the program was fed at each decode step."""
     fp8 = mode == "fp8"
     model = cfg["model"]
     m_items = weights.frozen(dict(model, norm_eps=cfg["norm_eps"]))
     with jax.default_matmul_precision("highest"):
-        sem = weights.build(seed, model, vpad, slots, rank)
+        sem = weights.build(arch, seed, model, vpad, slots, rank)
         tok = jnp.asarray(tokens.T)                      # (B, P)
         ids = jnp.asarray(idx.T)
         x = jnp.take(sem["embed"], tok, axis=0).astype(jnp.float32)
-        rows = row_block(model, tok.shape[0])
-        for layer in range(model["n_layers"]):
-            x = _layer(sem, layer, x, ids, m_items, fp8, rows)
+        rows = arch.row_block(model, tok.shape[0])
+        for tensors, g in zip(sem["groups"],
+                              arch.groups(model, slots, rank)):
+            for layer in range(g["n"]):
+                x = _layer(tensors, layer, x, ids, arch, g["name"], m_items,
+                           fp8, rows)
         r = jnp.asarray([b for _, b in at])
         c = jnp.asarray([p for p, _ in at])
         out = _logits(sem, x, r, c, m_items, fp8, model["vocab_size"])

@@ -3,9 +3,11 @@
     python3 bench/run.py --workload phi4.tenants --seed 7 --seconds 20 --trace 0
 
 From the root of a checkout.  The cell (``BENCHMARK.json``'s
-``workloads``) names a configuration (``bench/configs/<name>.json``) and a
-traffic mix (``bench/traffic/<mix>.json``).  The run builds the weights
-and LoRA bank from the seed, builds the program's ``Model``,
+``workloads``) names a configuration (``bench/configs/<name>.json``, which
+names its architecture's module ``bench/arch/<arch>.py``) and a traffic mix
+(``bench/traffic/<mix>.json``; the pair's knee is in
+``bench/knees/<config>.<mix>.json``).  The run
+builds the weights and LoRA bank from the seed, builds the program's ``Model``,
 ``JaxExecutor`` and ``ServingEngine`` through their public constructors,
 warms up the decode step (the executor's constructor compiles and runs
 it), drives the mix on the wall clock for ``fill_s`` seconds and then
@@ -139,7 +141,7 @@ def execute(jax, prog, spec, seed: int, seconds: float, trace: bool,
 
     import traffic
     import weights
-    cfg, mix = spec["config"], spec["traffic"]
+    cfg, mix, arch = spec["config"], spec["traffic"], spec["arch"]
     dev = jax.devices()[0]
     m = cfg["model"]
     rows, cache_len = cfg["serving"]["batch"], cfg["serving"]["cache_len"]
@@ -151,8 +153,8 @@ def execute(jax, prog, spec, seed: int, seconds: float, trace: bool,
     want_lora = jax.eval_shape(
         lambda k: model.init_lora(k, slots, rank), key)
     vpad = want_params["embed"]["embed"].shape[0]
-    sem = weights.build(seed, m, vpad, slots, rank)
-    params, lora = weights.to_program(sem)
+    sem = weights.build(arch, seed, m, vpad, slots, rank)
+    params, lora = weights.to_program(arch, sem)
     if not (weights.same_layout(params, want_params)
             and weights.same_layout(lora, want_lora)):
         raise RunFailed("the program's parameter layout is not the one "
@@ -165,7 +167,7 @@ def execute(jax, prog, spec, seed: int, seconds: float, trace: bool,
         kv_capacity_tokens=rows * cache_len, adapter_slots=slots,
         max_running=rows), ex)
     t_built = time.perf_counter()
-    planned = traffic.plan(mix, cfg["name"], rows, mix["fill_s"] + seconds)
+    planned = traffic.plan(mix, spec["knee"], rows, mix["fill_s"] + seconds)
     driver = Driver(jax, engine, ex, planned, cache_len=cache_len,
                     fill_s=mix["fill_s"], window_s=seconds,
                     sample_steps=mix["sample_steps"], seed=seed,
@@ -195,6 +197,7 @@ def execute(jax, prog, spec, seed: int, seconds: float, trace: bool,
         "bytes_limit": stats.get("bytes_limit"),
         "win": (driver.win0, driver.win1),
         "n_warm": sum(p.warm for p in planned),
+        "rate": traffic.rate(mix, spec["knee"]),
         "lateness": driver.lateness,
         "vpad": vpad, "rows": rows, "cache_len": cache_len,
         "slots": slots, "rank": rank,
@@ -219,13 +222,14 @@ def execute(jax, prog, spec, seed: int, seconds: float, trace: bool,
     return out
 
 
-def compare(seed: int, cfg: dict, out: dict) -> tuple:
+def compare(arch, seed: int, cfg: dict, out: dict) -> tuple:
     """(reference logits, the program's greedy-token gaps, its relative
     logit errors), one per sampled (step, row) pair."""
     import check
     import reference
-    ref = reference.logits(seed, cfg, out["vpad"], out["slots"], out["rank"],
-                           out["fed_tok"], out["fed_idx"], out["at"])
+    ref = reference.logits(arch, seed, cfg, out["vpad"], out["slots"],
+                           out["rank"], out["fed_tok"], out["fed_idx"],
+                           out["at"])
     return (ref, check.gaps(out["prog_logits"], ref),
             check.rel_err(out["prog_logits"], ref))
 
@@ -276,8 +280,9 @@ def run(argv=None, *, require_chip: bool = True, shrink=None,
         f"; compile or load s {watch.compile_s():.3f}; persistent-cache "
         f"hits {watch.cache_hits}, misses {watch.misses or 'none'}")
     log(f"window: {args.seconds} s, {len(steps)} engine steps, decode steps "
-        f"{n_fed} of {cache_len} positions, {out['n_tok']} tokens; requests "
-        f"due {len(out['uids'])} (+{out['n_warm']} in flight at the start); "
+        f"{n_fed} of {cache_len} positions, {out['n_tok']} tokens; offered "
+        f"{out['rate']} req/s, requests due {len(out['uids'])} "
+        f"(+{out['n_warm']} in flight at the start); "
         f"TTFT samples {len(ttft)}, gaps {len(gaps)}; generator lateness "
         f"mean {1e3 * statistics.fmean(lat):.3f} ms max "
         f"{1e3 * max(lat):.3f} ms")
@@ -293,7 +298,7 @@ def run(argv=None, *, require_chip: bool = True, shrink=None,
 
     # -- correct: the sampled logits against the plain reference -------------
     t_ref = time.perf_counter()
-    _, gap, err = compare(args.seed, cfg, out)
+    _, gap, err = compare(spec["arch"], args.seed, cfg, out)
     gap_max, err_max = float(gap.max()), float(err.max())
     checks = {
         "logit_gap": {"value": gap_max, "limit": cfg["check"]["gap_limit"]},
@@ -328,7 +333,7 @@ def run(argv=None, *, require_chip: bool = True, shrink=None,
                     "admitted": sum(s.admitted for s in steps),
                     "fed_idx": out["fed_idx"]}
         ctx = {"counters": counters, "trace": trace, "config": cfg,
-               "traffic": mix, "rank": out["rank"],
+               "arch": spec["arch"], "traffic": mix, "rank": out["rank"],
                "device_kind": dev.device_kind}
         for mt in spec["per_layer"]:
             value = load_reader(mt["name"])(ctx)

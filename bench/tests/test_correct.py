@@ -21,9 +21,16 @@ sys.path.insert(0, str(BENCH))
 sys.path.insert(0, str(BENCH / "tests"))
 sys.path.insert(0, str(BENCH.parent / "src"))
 
+import cell as cell_lib  # noqa: E402
 import tiny  # noqa: E402
 
 CELL = "phi4.tenants"
+
+
+def cpu_limits():
+    """(gap, err) limits of the cell's configuration at its CPU size."""
+    c = cell_lib.load(CELL)["config"]["cpu"]["check"]
+    return c["gap_limit"], c["err_limit"]
 
 
 def run_once(seed, capsys):
@@ -43,7 +50,7 @@ def test_control_fails_the_limit():
     seeds = [401, 402, 403]
     got = control.readings(CELL, 3.0, seeds, set(seeds),
                            require_chip=False, shrink=tiny.shrink)
-    gap, err = tiny.LIMITS["phi4-mini-3.8b"]
+    gap, err = cpu_limits()
     assert got["gap_lower"] <= gap
     assert got["err_lower"] <= err < got["err_upper"]
 
@@ -74,6 +81,6 @@ def test_fault_turns_correct_false(fault, monkeypatch, capsys):
     result = run_once(2 ** 31 + 99, capsys)
     assert result["correct"] is False
     checks = result["checks"]
-    gap, err = tiny.LIMITS["phi4-mini-3.8b"]
+    gap, err = cpu_limits()
     assert (checks["logit_gap"]["value"] > gap
             or checks["logit_err"]["value"] > err)

@@ -1,23 +1,12 @@
-"""A cell's configuration at reduced widths, for runs on the CPU.
-
-The limits here are the tiny size's own, set from CPU readings of
-``control.readings`` at this size over 12 seeds (PERF.md): phi4's largest
-logit gap 0.0016 and relative logit error 0.0102 against the float8
-control's smallest 0.0054 and 0.0863; internlm2's (untied head, larger
-logits) 0.0094 and 0.0116 against 0.0 and 0.0860 -- at this size the
-control keeps every greedy token on some seeds, and only the error
-separates.
-"""
+"""A cell's configuration at the reduced widths of its file's ``cpu``
+block, for runs on the CPU: the block's ``model`` and ``serving`` sizes
+replace the published ones, and its ``check`` holds the tiny size's own
+limits (the block's ``why`` gives the readings they were set from)."""
 import copy
-
-LIMITS = {"phi4-mini-3.8b": (0.003, 0.03), "internlm2-20b-pp4": (0.03, 0.03)}
 
 
 def shrink(cfg: dict) -> dict:
     cfg = copy.deepcopy(cfg)
-    cfg["model"].update(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
-                        d_ff=128, vocab_size=512, head_dim=16)
-    cfg["serving"].update(batch=4, cache_len=8192)
-    gap, err = LIMITS[cfg["name"]]
-    cfg["check"].update(gap_limit=gap, err_limit=err)
+    for part in ("model", "serving", "check"):
+        cfg[part].update(cfg["cpu"][part])
     return cfg

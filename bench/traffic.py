@@ -70,13 +70,10 @@ def popularity(mix: dict) -> np.ndarray:
     return p / p.sum()
 
 
-def rate(mix: dict, config_name: str) -> float:
-    """Offered requests per second: a share of the configuration's knee."""
-    knees = mix["knee_req_s"]
-    if knees.get(config_name) is None:
-        raise ValueError(f"traffic {mix['name']!r} has no knee for "
-                         f"configuration {config_name!r}")
-    return mix["rate_share_of_knee"] * knees[config_name]
+def rate(mix: dict, knee: float) -> float:
+    """Offered requests per second: a share of the knee, the configuration's
+    under this mix (``bench/knees/<config>.<mix>.json``)."""
+    return mix["rate_share_of_knee"] * knee
 
 
 def mean_output(mix: dict) -> float:
@@ -86,7 +83,7 @@ def mean_output(mix: dict) -> float:
     return float(outs.mean())
 
 
-def plan(mix: dict, config_name: str, rows: int,
+def plan(mix: dict, knee: float, rows: int,
          span_s: float) -> List[Planned]:
     """Every request of one run that is due before ``span_s``.
 
@@ -102,7 +99,7 @@ def plan(mix: dict, config_name: str, rows: int,
     ``round(rate * span_s)``: times uniform over the span, sorted."""
     if mix["arrivals"] != "poisson":
         raise ValueError(f"unknown arrivals {mix['arrivals']!r}")
-    r = rate(mix, config_name)
+    r = rate(mix, knee)
     trace = np.random.default_rng(mix["trace_seed"])
     n = int(round(r * span_s))
     due = np.sort(trace.uniform(0.0, span_s, n))
